@@ -40,6 +40,7 @@ Sections:
 
 import argparse
 import csv
+import sys
 
 
 SECTIONS = ["reliability", "performance", "snapshot", "straggler",
@@ -53,6 +54,7 @@ def main() -> None:
     args = ap.parse_args()
 
     rows: list[dict] = []
+    failed: list[str] = []
     sections = [args.only] if args.only else SECTIONS
     for name in sections:
         print("\n" + "=" * 72)
@@ -80,10 +82,11 @@ def main() -> None:
             elif name == "latency":
                 from benchmarks import latency_bench as m
             m.main(rows)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other sections, fail at the end
             print(f"SECTION FAILED: {name}: {type(e).__name__}: {e}")
             import traceback
             traceback.print_exc()
+            failed.append(name)
 
     if args.csv:
         keys = sorted({k for r in rows for k in r})
@@ -92,6 +95,8 @@ def main() -> None:
             w.writeheader()
             w.writerows(rows)
         print(f"\nwrote {len(rows)} rows to {args.csv}")
+    if failed:
+        sys.exit(f"{len(failed)} section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

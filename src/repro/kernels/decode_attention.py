@@ -9,9 +9,10 @@ a ``(bk, K, D)`` cache tile, and per-head statistics carry in VMEM scratch
 across k-blocks. GQA is computed by reshaping H into (K, G) groups inside
 the kernel — again no head expansion in HBM.
 
-Per-sequence valid ``lengths`` mask the cache tail; blocks entirely past
-``lengths[b]`` are skipped with ``pl.when`` (a decode over a 32k cache at
-length 1k does 1/32 of the block iterations' work).
+Per-sequence valid ``lengths`` (scalar-prefetched into SMEM) mask the
+cache tail; blocks entirely past ``lengths[b]`` are skipped with
+``pl.when`` (a decode over a 32k cache at length 1k does 1/32 of the block
+iterations' work).
 """
 
 from __future__ import annotations
@@ -27,7 +28,7 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    len_ref,   # (1, 1) int32
+    len_ref,   # scalar prefetch (B,) int32
     q_ref,     # (1, H, D)
     k_ref,     # (1, bk, K, D)
     v_ref,     # (1, bk, K, D)
@@ -41,7 +42,7 @@ def _decode_kernel(
 ):
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -93,9 +94,11 @@ def decode_attention(
     v: jax.Array,        # (B, S, K, D)
     lengths: jax.Array,  # (B,) int32
     *,
-    block_k: int = 512,
+    block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    # block_k 128: at 256 the f32 working copies of an 8-kv-head tile
+    # overflow v5e's 16 MiB scoped VMEM (phi4-mini widths)
     B, H, D = q.shape
     S, K = k.shape[1], k.shape[2]
     assert H % K == 0, (H, K)
@@ -107,24 +110,26 @@ def decode_attention(
         k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
     nk = (S + pk) // bk
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
 
     kernel = functools.partial(_decode_kernel, block_k=bk, scale=scale)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, ki: (b, 0)),
-            pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, bk, K, D), lambda b, ki: (b, ki, 0, 0)),
-            pl.BlockSpec((1, bk, K, D), lambda b, ki: (b, ki, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, ki, lens: (b, 0, 0)),
+            pl.BlockSpec((1, bk, K, D), lambda b, ki, lens: (b, ki, 0, 0)),
+            pl.BlockSpec((1, bk, K, D), lambda b, ki, lens: (b, ki, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, ki, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H,), jnp.float32),
             pltpu.VMEM((H,), jnp.float32),
             pltpu.VMEM((H, D), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(lens, q, k, v)
+    )(lengths.astype(jnp.int32), q, k, v)

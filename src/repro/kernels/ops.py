@@ -13,52 +13,65 @@ The XLA implementations are *algorithmically identical* to the Pallas kernels
 dry-run reflects the kernelized execution. ``ref.py`` holds the simple oracles
 both are tested against.
 
-The process-wide default backend comes from the ``REPRO_KERNEL_BACKEND``
-environment variable (``xla`` when unset) — how CI runs the whole test
-suite once per backend without touching test code; ``use_backend`` still
-overrides it per scope.
+The process-wide default backend is resolved on first use, never at
+import: ``REPRO_KERNEL_BACKEND`` when set (how CI runs the whole test suite
+once per backend without touching test code), else ``pallas`` when JAX's
+default backend is a TPU and ``xla`` elsewhere. ``use_backend`` overrides
+it per scope. The scope is part of every ``jax.jit`` cache key, so a
+function traced under one backend is retraced, not reused, under another.
+On a TPU no op runs in interpret mode.
 """
 
 from __future__ import annotations
 
 import contextlib
-import contextvars
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
-from repro.parallel import tracing
+from repro.parallel import partition, tracing
 
 _BACKENDS = ("xla", "pallas", "pallas_interpret")
-_DEFAULT_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "xla")
-if _DEFAULT_BACKEND not in _BACKENDS:
-    raise ValueError(
-        f"REPRO_KERNEL_BACKEND={_DEFAULT_BACKEND!r}: expected one of "
-        f"{_BACKENDS}"
-    )
 
-_BACKEND: contextvars.ContextVar[str] = contextvars.ContextVar(
-    "repro_kernel_backend", default=_DEFAULT_BACKEND
-)
+# None = the process default; any other value is a use_backend() scope
+_SCOPE = jax.make_user_context(default_value=None)
 
 NEG_INF = -1e30
 
 
+@functools.cache
+def default_backend() -> str:
+    name = os.environ.get("REPRO_KERNEL_BACKEND")
+    if name is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if name not in _BACKENDS:
+        raise ValueError(
+            f"REPRO_KERNEL_BACKEND={name!r}: expected one of {_BACKENDS}"
+        )
+    return name
+
+
 def current_backend() -> str:
-    return _BACKEND.get()
+    name = _SCOPE.value or default_backend()
+    if name == "pallas_interpret" and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "kernel backend 'pallas_interpret' runs Pallas kernels in "
+            "interpret mode, which is for CPU validation only; use 'pallas' "
+            "on a TPU"
+        )
+    return name
 
 
 @contextlib.contextmanager
 def use_backend(name: str):
     """Context manager selecting the kernel backend ("xla", "pallas", "pallas_interpret")."""
     assert name in _BACKENDS, name
-    tok = _BACKEND.set(name)
-    try:
+    with _SCOPE(name):
         yield
-    finally:
-        _BACKEND.reset(tok)
 
 
 def _pallas(name: str):
@@ -66,6 +79,32 @@ def _pallas(name: str):
     import importlib
 
     return importlib.import_module(f"repro.kernels.{name}")
+
+
+def _kernel_axes(batch: int, *heads: int):
+    """Axes of the installed activation mesh that a kernel's batch dim and
+    head dims split over (None keeps the dim whole; both None without a
+    mesh): batch over the data axes, heads over ``model`` only when every
+    head count divides it, so each shard keeps whole GQA groups."""
+    mesh = partition.current_mesh()
+    if mesh is None:
+        return None, None
+    data = partition.data_axes(mesh)
+    b = data if data and batch % partition.axis_size(mesh, data) == 0 else None
+    m = ("model" if "model" in mesh.axis_names
+         and all(h % mesh.shape["model"] == 0 for h in heads) else None)
+    return b, m
+
+
+def _per_shard(kernel, args, in_specs, out_specs):
+    """Call a Pallas kernel once per shard of the installed activation mesh
+    (:func:`repro.parallel.partition.activation_sharding`): XLA cannot
+    partition a Mosaic kernel by itself. Without a mesh, one plain call."""
+    mesh = partition.current_mesh()
+    if mesh is None:
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -78,7 +117,10 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
     if b == "xla":
         return ref.rmsnorm(x, w, eps)
     mod = _pallas("rmsnorm")
-    return mod.rmsnorm(x, w, eps, interpret=(b == "pallas_interpret"))
+    kernel = functools.partial(mod.rmsnorm, eps=eps,
+                               interpret=(b == "pallas_interpret"))
+    spec = P(_kernel_axes(x.shape[0])[0], *(None,) * (x.ndim - 1))
+    return _per_shard(kernel, (x, w), (spec, P()), spec)
 
 
 # ---------------------------------------------------------------------------
@@ -110,11 +152,14 @@ def attention(
             block_q=block_q, block_k=block_k,
         )
     mod = _pallas("flash_attention")
-    return mod.flash_attention(
-        q, k, v, causal=causal, q_offset=q_offset,
+    kernel = functools.partial(
+        mod.flash_attention, causal=causal, q_offset=q_offset,
         block_q=block_q, block_k=block_k,
         interpret=(b == "pallas_interpret"),
     )
+    bt, m = _kernel_axes(q.shape[0], q.shape[2], k.shape[2])
+    spec = P(bt, None, m, None)
+    return _per_shard(kernel, (q, k, v), (spec, spec, spec), spec)
 
 
 def _flash_attention_xla(q, k, v, *, causal, q_offset, block_q, block_k):
@@ -197,9 +242,12 @@ def decode_attention(
     if b == "xla":
         return _decode_attention_xla(q, k, v, lengths)
     mod = _pallas("decode_attention")
-    return mod.decode_attention(
-        q, k, v, lengths, interpret=(b == "pallas_interpret")
-    )
+    kernel = functools.partial(mod.decode_attention,
+                               interpret=(b == "pallas_interpret"))
+    bt, m = _kernel_axes(q.shape[0], q.shape[1], k.shape[2])
+    kv = P(bt, None, m, None)
+    return _per_shard(kernel, (q, k, v, lengths),
+                      (P(bt, m, None), kv, kv, P(bt)), P(bt, m, None))
 
 
 def _decode_attention_xla(q, k, v, lengths):
@@ -235,11 +283,24 @@ def paged_decode_attention(
     if b == "xla":
         return _paged_decode_attention_xla(q, k_pages, v_pages, page_table,
                                            lengths)
+    return _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths,
+                                interpret=(b == "pallas_interpret"))
+
+
+def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
+                         interpret):
+    """The paged flash-decode kernel, split per shard under a mesh: lanes
+    over the data axes, query heads and the pool's kv heads over
+    ``model`` (page ids stay global, so the pool's page dim never
+    splits)."""
     mod = _pallas("paged_decode_attention")
-    return mod.paged_decode_attention(
-        q, k_pages, v_pages, page_table, lengths,
-        interpret=(b == "pallas_interpret"),
-    )
+    kernel = functools.partial(mod.paged_decode_attention,
+                               interpret=interpret)
+    bt, m = _kernel_axes(q.shape[0], q.shape[1], k_pages.shape[2])
+    pool = P(None, None, m, None)
+    return _per_shard(kernel, (q, k_pages, v_pages, page_table, lengths),
+                      (P(bt, m, None), pool, pool, P(bt, None), P(bt)),
+                      P(bt, m, None))
 
 
 def _paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths):
@@ -280,8 +341,7 @@ def paged_verify_attention(
     # masking contract.
     B, W, H, D = q.shape
     lengths = (positions[:, None] + jnp.arange(W)[None, :] + 1).reshape(-1)
-    mod = _pallas("paged_decode_attention")
-    out = mod.paged_decode_attention(
+    out = _paged_decode_pallas(
         q.reshape(B * W, H, D), k_pages, v_pages,
         jnp.repeat(page_table, W, axis=0), lengths.astype(jnp.int32),
         interpret=(b == "pallas_interpret"),
@@ -340,8 +400,7 @@ def paged_cross_attention(
     # non-causal over paged KV" is exactly its contract, and every folded
     # lane shares its sequence's page table and length.
     B, C, H, D = q.shape
-    mod = _pallas("paged_decode_attention")
-    out = mod.paged_decode_attention(
+    out = _paged_decode_pallas(
         q.reshape(B * C, H, D), k_pages, v_pages,
         jnp.repeat(page_table, C, axis=0), jnp.repeat(lengths, C, axis=0),
         interpret=(b == "pallas_interpret"),
@@ -423,9 +482,18 @@ def selective_scan(
     b = current_backend()
     if b in ("pallas", "pallas_interpret"):
         mod = _pallas("selective_scan")
-        return mod.selective_scan(
-            x, dt, A, Bm, C, D, h0, chunk=chunk,
-            interpret=(b == "pallas_interpret"),
+        kernel = functools.partial(mod.selective_scan, chunk=chunk,
+                                   interpret=(b == "pallas_interpret"))
+        if h0 is None:
+            h0 = jnp.zeros((x.shape[0], *A.shape), jnp.float32)
+        bt, m = _kernel_axes(x.shape[0], x.shape[2])
+        chans = P(bt, None, m)
+        seq = P(bt, None, None)
+        state = P(bt, m, None)
+        return _per_shard(
+            kernel, (x, dt, A, Bm, C, D, h0),
+            (chans, chans, P(m, None), seq, seq, P(m), state),
+            (chans, state),
         )
     return _selective_scan_xla(x, dt, A, Bm, C, D, h0, chunk=chunk,
                                compute_dtype=compute_dtype)
@@ -524,9 +592,19 @@ def ssd(
     b = current_backend()
     if b in ("pallas", "pallas_interpret"):
         mod = _pallas("ssd")
-        return mod.ssd(
-            x, dt, A, Bm, C, D, h0, chunk=chunk,
-            interpret=(b == "pallas_interpret"),
+        kernel = functools.partial(mod.ssd, chunk=chunk,
+                                   interpret=(b == "pallas_interpret"))
+        if h0 is None:
+            h0 = jnp.zeros((*x.shape[:1], *x.shape[2:], Bm.shape[-1]),
+                           jnp.float32)
+        bt, m = _kernel_axes(x.shape[0], x.shape[2])
+        seq = P(bt, None, None)
+        state = P(bt, m, None, None)
+        return _per_shard(
+            kernel, (x, dt, A, Bm, C, D, h0),
+            (P(bt, None, m, None), P(bt, None, m), P(m), seq, seq, P(m),
+             state),
+            (P(bt, None, m, None), state),
         )
     return _ssd_xla(x, dt, A, Bm, C, D, h0, chunk=chunk)
 

@@ -3,14 +3,17 @@
 TPU adaptation: the recurrence ``h_t = dA_t·h_{t-1} + dB_t·x_t`` is
 processed in VMEM-resident chunks — grid ``(batch, channel_blocks,
 seq_chunks)``, where the sequence dim iterates sequentially and the
-``(bc, N)`` carried state lives in VMEM scratch across chunk steps. Inside
-a chunk the scan runs as a log-depth associative scan over the chunk's
-``(c, bc, N)`` transition/update tensors (VPU work), so HBM sees each
-input exactly once. Channels block at 128 lanes (VPU width); the state
-dim N (=16 for falcon-mamba) stays whole.
+carried state lives in VMEM scratch across chunk steps. Inside a chunk
+the recurrence steps token by token (``fori_loop``) over an ``(N, bc)``
+state tile: the state dim on sublanes, 128 channels on lanes, so every
+step is a few full-vreg VPU ops and HBM sees each input once. ``B`` and
+``C`` arrive as ``(N, 1)`` columns per token, which broadcast across the
+channel lanes without a transpose in the kernel. (An in-chunk
+associative scan does not lower to Mosaic: "Invalid type" at 256 steps,
+"interior padding" at 50.)
 
-Layouts follow the XLA fallback in ``repro.kernels.ops`` so the two paths
-are drop-in interchangeable.
+Layouts at the call boundary follow the XLA fallback in
+``repro.kernels.ops`` so the two paths are drop-in interchangeable.
 """
 
 from __future__ import annotations
@@ -24,16 +27,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _scan_kernel(
-    x_ref,    # (1, c, bc)
-    dt_ref,   # (1, c, bc)
-    A_ref,    # (bc, N)
-    B_ref,    # (1, c, N)
-    C_ref,    # (1, c, N)
-    D_ref,    # (bc,)
-    h0_ref,   # (1, bc, N)
-    y_ref,    # (1, c, bc)  out
-    hT_ref,   # (1, bc, N)  out (final state)
-    h_ref,    # scratch (bc, N) — carried state
+    x_ref,    # (1, c, bc) f32
+    dt_ref,   # (1, c, bc) f32
+    At_ref,   # (N, bc) — A transposed
+    B_ref,    # (1, c, N, 1) — one column per token
+    C_ref,    # (1, c, N, 1)
+    D_ref,    # (1, bc)
+    h0_ref,   # (1, N, bc)
+    y_ref,    # (1, c, bc) f32 out
+    hT_ref,   # (1, N, bc) out (final state)
+    h_ref,    # scratch (N, bc) — carried state
+    *,
+    chunk: int,
 ):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
@@ -42,30 +47,25 @@ def _scan_kernel(
     def _init():
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)       # (c, bc)
-    dt = dt_ref[0].astype(jnp.float32)     # (c, bc)
-    A = A_ref[...].astype(jnp.float32)     # (bc, N)
-    Bm = B_ref[0].astype(jnp.float32)      # (c, N)
-    C = C_ref[0].astype(jnp.float32)       # (c, N)
+    At = At_ref[...].astype(jnp.float32)
+    D = D_ref[...].astype(jnp.float32)
 
-    dA = jnp.exp(dt[:, :, None] * A[None])             # (c, bc, N)
-    dBx = (dt * x)[:, :, None] * Bm[:, None, :]        # (c, bc, N)
+    def step(t, h):
+        dt = dt_ref[0, pl.ds(t, 1), :]                      # (1, bc)
+        x = x_ref[0, pl.ds(t, 1), :]
+        h = (jnp.exp(At * dt) * h
+             + B_ref[0, t].astype(jnp.float32) * (dt * x))  # (N, bc)
+        y = jnp.sum(h * C_ref[0, t].astype(jnp.float32), axis=0,
+                    keepdims=True)                          # (1, bc)
+        y_ref[0, pl.ds(t, 1), :] = y + D * x
+        return h
 
-    def combine(e1, e2):
-        a1, b1 = e1
-        a2, b2 = e2
-        return a2 * a1, a2 * b1 + b2
-
-    aa, bb = jax.lax.associative_scan(combine, (dA, dBx), axis=0)
-    hs = aa * h_ref[...][None] + bb                     # (c, bc, N)
-    y = jnp.einsum("cbn,cn->cb", hs, C)
-    y = y + D_ref[...].astype(jnp.float32)[None] * x
-    y_ref[0] = y.astype(y_ref.dtype)
-    h_ref[...] = hs[-1]
+    h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
+    h_ref[...] = h
 
     @pl.when(ci == nc - 1)
     def _finish():
-        hT_ref[0] = h_ref[...]
+        hT_ref[0] = h
 
 
 @functools.partial(
@@ -93,15 +93,17 @@ def selective_scan(
     bc = min(block_channels, Di)
     ps = (-S) % c
     pc = (-Di) % bc
+    f32 = jnp.float32
+    xs, dts = x.astype(f32), dt.astype(f32)
     if ps:
         # padded timesteps: dt=0 -> dA=1, dBx=0 (identity transitions)
-        x = jnp.pad(x, ((0, 0), (0, ps), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, ps), (0, 0)))
+        xs = jnp.pad(xs, ((0, 0), (0, ps), (0, 0)))
+        dts = jnp.pad(dts, ((0, 0), (0, ps), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, ps), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, ps), (0, 0)))
     if pc:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pc)))
-        dt = jnp.pad(dt, ((0, 0), (0, 0), (0, pc)))
+        xs = jnp.pad(xs, ((0, 0), (0, 0), (0, pc)))
+        dts = jnp.pad(dts, ((0, 0), (0, 0), (0, pc)))
         A = jnp.pad(A, ((0, pc), (0, 0)))
         D = jnp.pad(D, ((0, pc),))
         h0 = jnp.pad(h0, ((0, 0), (0, pc), (0, 0)))
@@ -109,26 +111,28 @@ def selective_scan(
     ncs, ncb = Sp // c, Dp // bc
 
     y, hT = pl.pallas_call(
-        _scan_kernel,
+        functools.partial(_scan_kernel, chunk=c),
         grid=(B, ncb, ncs),
         in_specs=[
             pl.BlockSpec((1, c, bc), lambda b, cb, ci: (b, ci, cb)),
             pl.BlockSpec((1, c, bc), lambda b, cb, ci: (b, ci, cb)),
-            pl.BlockSpec((bc, N), lambda b, cb, ci: (cb, 0)),
-            pl.BlockSpec((1, c, N), lambda b, cb, ci: (b, ci, 0)),
-            pl.BlockSpec((1, c, N), lambda b, cb, ci: (b, ci, 0)),
-            pl.BlockSpec((bc,), lambda b, cb, ci: (cb,)),
-            pl.BlockSpec((1, bc, N), lambda b, cb, ci: (b, cb, 0)),
+            pl.BlockSpec((N, bc), lambda b, cb, ci: (0, cb)),
+            pl.BlockSpec((1, c, N, 1), lambda b, cb, ci: (b, ci, 0, 0)),
+            pl.BlockSpec((1, c, N, 1), lambda b, cb, ci: (b, ci, 0, 0)),
+            pl.BlockSpec((1, bc), lambda b, cb, ci: (0, cb)),
+            pl.BlockSpec((1, N, bc), lambda b, cb, ci: (b, 0, cb)),
         ],
         out_specs=[
             pl.BlockSpec((1, c, bc), lambda b, cb, ci: (b, ci, cb)),
-            pl.BlockSpec((1, bc, N), lambda b, cb, ci: (b, cb, 0)),
+            pl.BlockSpec((1, N, bc), lambda b, cb, ci: (b, 0, cb)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sp, Dp), x.dtype),
-            jax.ShapeDtypeStruct((B, Dp, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, Sp, Dp), f32),
+            jax.ShapeDtypeStruct((B, N, Dp), f32),
         ],
-        scratch_shapes=[pltpu.VMEM((bc, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bc), f32)],
         interpret=interpret,
-    )(x, dt, A, Bm, C, D, h0)
-    return y[:, :S, :Di], hT[:, :Di]
+    )(xs, dts, A.T, Bm[..., None], C[..., None], D.reshape(1, Dp),
+      jnp.swapaxes(h0, 1, 2))
+    return (y[:, :S, :Di].astype(x.dtype),
+            jnp.swapaxes(hT, 1, 2)[:, :Di])

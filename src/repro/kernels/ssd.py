@@ -26,19 +26,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
-    x_ref,    # (1, c, 1, P)
-    dt_ref,   # (1, c, 1)
-    A_ref,    # (1,)
+    A_ref,    # scalar prefetch (Hs,) f32
+    D_ref,    # scalar prefetch (Hs,) f32
+    x_ref,    # (1, 1, c, P)
+    dtc_ref,  # (1, 1, c, 1) f32 — dt as a column
+    dtr_ref,  # (1, 1, 1, c) f32 — the same dt as a row
     B_ref,    # (1, c, N)
     C_ref,    # (1, c, N)
-    D_ref,    # (1,)
     h0_ref,   # (1, 1, P, N)
-    y_ref,    # (1, c, 1, P) out
+    y_ref,    # (1, 1, c, P) out
     hT_ref,   # (1, 1, P, N) out
     h_ref,    # scratch (P, N)
     *,
     chunk: int,
 ):
+    head = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -46,35 +48,41 @@ def _ssd_kernel(
     def _init():
         h_ref[...] = h0_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)       # (c, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)     # (c,)
-    a = A_ref[0].astype(jnp.float32)             # ()
+    x = x_ref[0, 0].astype(jnp.float32)          # (c, P)
+    a = A_ref[head]
+    da_col = dtc_ref[0, 0] * a                   # (c, 1)
+    dt_row = dtr_ref[0, 0]                       # (1, c)
     Bm = B_ref[0].astype(jnp.float32)            # (c, N)
     C = C_ref[0].astype(jnp.float32)             # (c, N)
 
-    da = dt * a                                  # (c,)
-    l = jnp.cumsum(da)                           # (c,) inclusive
+    # inclusive cumsum l = cumsum(dt·a), as a column and as a row, by
+    # masked reductions (VPU/XLU work, no scan primitive in the kernel)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = ii >= jj
+    l_col = jnp.sum(jnp.where(causal, dt_row * a, 0.0), axis=1,
+                    keepdims=True)                             # (c, 1)
+    l_row = jnp.sum(jnp.where(ii <= jj, da_col, 0.0), axis=0,
+                    keepdims=True)                             # (1, c)
     # intra-chunk: masked decay Gram matmul
     g = jax.lax.dot_general(C, Bm, (((1,), (1,)), ((), ())))   # (c, c)
-    ldiff = l[:, None] - l[None, :]
-    ii = jax.lax.iota(jnp.int32, chunk)
-    causal = ii[:, None] >= ii[None, :]
-    decay = jnp.where(causal, jnp.exp(ldiff), 0.0)
-    m = g * decay * dt[None, :]                                # (c, c)
+    decay = jnp.where(causal, jnp.exp(l_col - l_row), 0.0)
+    m = g * decay * dt_row                                     # (c, c)
     y_intra = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())))  # (c, P)
     # inter-chunk: carried state contribution
     h = h_ref[...]
-    y_inter = jnp.exp(l)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(l_col) * jax.lax.dot_general(
         C, h, (((1,), (1,)), ((), ()))
     )                                                          # (c, P)
-    y = y_intra + y_inter + D_ref[0].astype(jnp.float32) * x
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
-    # next state: h' = exp(l_last) h + Σ_j w_j B_j ⊗ x_j,  w_j = exp(l_last-l_j) dt_j
-    w = jnp.exp(l[-1] - l) * dt                                # (c,)
+    y = y_intra + y_inter + D_ref[head] * x
+    y_ref[0, 0] = y.astype(y_ref.dtype)
+    # next state: h' = exp(l_last) h + Σ_j w_j x_j ⊗ B_j,  w_j = exp(l_last-l_j) dt_j
+    l_last = l_col[chunk - 1:, :]                              # (1, 1)
+    w = jnp.exp(l_last - l_col) * dtc_ref[0, 0]                # (c, 1)
     s = jax.lax.dot_general(
-        x * w[:, None], Bm, (((0,), (0,)), ((), ()))
+        x * w, Bm, (((0,), (0,)), ((), ()))
     )                                                          # (P, N)
-    h_ref[...] = jnp.exp(l[-1]) * h + s
+    h_ref[...] = jnp.exp(l_last) * h + s
 
     @pl.when(ci == nc - 1)
     def _finish():
@@ -109,27 +117,35 @@ def ssd(
     Sp = S + ps
     ncs = Sp // c
 
-    y, hT = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=c),
+    # head-major layout: every block's trailing two dims are whole
+    # (c, P) / (c, 1) / (1, c) tiles, as the TPU's (8, 128) tiling needs
+    xt = jnp.swapaxes(x, 1, 2)                       # (B, Hs, Sp, P)
+    dtt = jnp.swapaxes(dt, 1, 2).astype(jnp.float32)  # (B, Hs, Sp)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, Hs, ncs),
         in_specs=[
-            pl.BlockSpec((1, c, 1, P), lambda b, h, ci: (b, ci, h, 0)),
-            pl.BlockSpec((1, c, 1), lambda b, h, ci: (b, ci, h)),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,)),
-            pl.BlockSpec((1, c, N), lambda b, h, ci: (b, ci, 0)),
-            pl.BlockSpec((1, c, N), lambda b, h, ci: (b, ci, 0)),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,)),
-            pl.BlockSpec((1, 1, P, N), lambda b, h, ci: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, c, P), lambda b, h, ci, *_: (b, h, ci, 0)),
+            pl.BlockSpec((1, 1, c, 1), lambda b, h, ci, *_: (b, h, ci, 0)),
+            pl.BlockSpec((1, 1, 1, c), lambda b, h, ci, *_: (b, h, 0, ci)),
+            pl.BlockSpec((1, c, N), lambda b, h, ci, *_: (b, ci, 0)),
+            pl.BlockSpec((1, c, N), lambda b, h, ci, *_: (b, ci, 0)),
+            pl.BlockSpec((1, 1, P, N), lambda b, h, ci, *_: (b, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, c, 1, P), lambda b, h, ci: (b, ci, h, 0)),
-            pl.BlockSpec((1, 1, P, N), lambda b, h, ci: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Sp, Hs, P), x.dtype),
-            jax.ShapeDtypeStruct((B, Hs, P, N), jnp.float32),
+            pl.BlockSpec((1, 1, c, P), lambda b, h, ci, *_: (b, h, ci, 0)),
+            pl.BlockSpec((1, 1, P, N), lambda b, h, ci, *_: (b, h, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+    )
+    y, hT = pl.pallas_call(
+        functools.partial(_ssd_kernel, chunk=c),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hs, Sp, P), x.dtype),
+            jax.ShapeDtypeStruct((B, Hs, P, N), jnp.float32),
+        ],
         interpret=interpret,
-    )(x, dt, A, Bm, C, D, h0)
-    return y[:, :S], hT
+    )(A.astype(jnp.float32), D.astype(jnp.float32), xt, dtt[..., None],
+      dtt[:, :, None, :], Bm, C, h0)
+    return jnp.swapaxes(y, 1, 2)[:, :S], hT

@@ -28,9 +28,3 @@ def make_host_mesh(n_data: int, n_model: int, devices=None) -> Mesh:
     assert len(devices) >= need, (len(devices), need)
     arr = np.array(devices[:need]).reshape(n_data, n_model)
     return Mesh(arr, ("data", "model"), axis_types=(AxisType.Auto, AxisType.Auto))
-
-
-# Hardware constants for the roofline (TPU v5e, per chip).
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_BW_PER_LINK = 50e9         # bytes/s/link

@@ -62,13 +62,13 @@ NEVER = {
 DATA_AXES_PREFERENCE = (("pod", "data"), ("data",))
 
 
-def _mesh_axis_size(mesh: Mesh, name) -> int:
+def axis_size(mesh: Mesh, name) -> int:
     if isinstance(name, tuple):
         return math.prod(mesh.shape[n] for n in name)
     return mesh.shape[name]
 
 
-def _data_axes(mesh: Mesh) -> tuple:
+def data_axes(mesh: Mesh) -> tuple:
     for cand in DATA_AXES_PREFERENCE:
         if all(a in mesh.axis_names for a in cand):
             return cand
@@ -88,7 +88,7 @@ def spec_for_axes(
     for i, (name, dim) in enumerate(zip(axes, shape)):
         if name == "batch":
             for cand in DATA_AXES_PREFERENCE:
-                if all(a in mesh.axis_names for a in cand) and dim % _mesh_axis_size(
+                if all(a in mesh.axis_names for a in cand) and dim % axis_size(
                     mesh, cand
                 ) == 0 and dim > 0:
                     entries[i] = cand if len(cand) > 1 else cand[0]

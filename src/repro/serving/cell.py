@@ -441,6 +441,9 @@ class ElasticServeCell:
             blob = self.engine.snapshot()
         elif self.engine is not None:
             blob = self._restorable_blob()
+        # the old incarnation lives on only in the blob: free its params
+        # and KV pool before the new layout lands on the same chips
+        self.engine = None
 
         for h in self.cell_hosts:       # release the old membership
             info = self.server.hosts.get(h)
@@ -475,8 +478,7 @@ class ElasticServeCell:
                 paged_cache_shardings(self.model, engine.n_slots,
                                       engine.n_pages, engine.page_size,
                                       self.mesh))
-        old_engine, self.engine = self.engine, engine
-        del old_engine
+        self.engine = engine
         self._sync_requests(restored)
         shed = self._apply_capacity(now)
 
@@ -534,7 +536,7 @@ class ElasticServeCell:
             self.mesh = mesh
         else:
             from jax.sharding import AbstractMesh
-            mesh = AbstractMesh((("data", data), ("model", model)))
+            mesh = AbstractMesh((data, model), ("data", "model"))
             self.mesh = None            # layout-only: no physical mesh
         p_specs = tree_partition_specs(self.param_axes, self.params_host,
                                        mesh)

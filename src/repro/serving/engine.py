@@ -114,6 +114,7 @@ from __future__ import annotations
 
 import base64
 import json
+import weakref
 import zlib
 from dataclasses import dataclass, field
 from typing import Any
@@ -271,7 +272,9 @@ class SlotLifecycle:
     """
 
     def __init__(self, engine: "ServeEngine"):
-        self.eng = engine
+        # a proxy, not a reference: a cycle would keep a dropped engine's
+        # params and KV pool on the device until the cyclic GC runs
+        self.eng = weakref.proxy(engine)
 
     def bind(self, slot: int, req: Request, chain: list[int]) -> None:
         """Install ``chain`` as the slot's page-table row and bind the

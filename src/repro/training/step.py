@@ -3,6 +3,9 @@
 Supports gradient accumulation (microbatching) via an inner ``lax.scan`` —
 also the mechanism straggler mitigation uses to rebalance work away from
 suspended hosts (see ``repro.training.straggler``).
+
+The step always traces its ops with the ``xla`` kernel backend: the Pallas
+kernels have no VJP, and a TPU defaults to them for serving.
 """
 
 from __future__ import annotations
@@ -11,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import RunConfig
+from repro.kernels import ops
 from repro.models.model_api import ModelFns
 from repro.optim import adamw_update
 from repro.parallel import tracing
@@ -31,6 +35,10 @@ def make_train_step(model: ModelFns, run: RunConfig):
         return loss, aux, grads
 
     def train_step(state, batch):
+        with ops.use_backend("xla"):
+            return _train_step(state, batch)
+
+    def _train_step(state, batch):
         params = state["params"]
         rng = jax.random.wrap_key_data(state["rng"])
         rng, comp_key = jax.random.split(rng)

@@ -62,6 +62,22 @@ def test_int8_compression_close_but_not_identical(setup):
     assert 0 < max(diffs) < 1e-2
 
 
+def test_train_step_differentiates_under_the_tpu_default_backend(
+        setup, monkeypatch):
+    """A TPU defaults the kernel backend to ``pallas``, whose kernels have
+    no VJP; the train step must still differentiate, through XLA."""
+    from repro.kernels import ops
+
+    cfg, model, state, batch = setup
+    run = RunConfig(arch=cfg.arch_id)
+    _, want = jax.jit(make_train_step(model, run))(state, batch)
+    monkeypatch.setattr(ops, "default_backend", lambda: "pallas")
+    assert ops.current_backend() == "pallas"
+    _, got = jax.jit(make_train_step(model, run))(state, batch)
+    assert np.isfinite(float(got["loss"]))
+    assert float(got["loss"]) == float(want["loss"])
+
+
 def test_grad_clipping_bounds_update(setup):
     cfg, model, state, batch = setup
     step = jax.jit(make_train_step(model, RunConfig(
