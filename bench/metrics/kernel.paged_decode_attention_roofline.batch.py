@@ -1,0 +1,7 @@
+"""The paged decode attention kernel's roofline time over its device
+time in the traced window, in percent."""
+from bench import mfu
+
+
+def read(ctx):
+    return mfu.roofline_share(ctx, "paged_decode_attention")
