@@ -50,7 +50,6 @@ CELL_SLOTS, CELL_MAX_SEQ, CELL_LO, CELL_HI = 4, 512, 64, 255
 # in interpret mode on a CPU. A wrong page, mask or head mapping moves
 # the logits by O(1).
 REL_TOL = 0.1
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 class SmokeFailure(AssertionError):
@@ -60,28 +59,6 @@ class SmokeFailure(AssertionError):
 def check(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
-
-
-class CompileClock:
-    """Seconds the XLA/TPU compiler runs, from JAX's own monitoring events
-    (compiles run one at a time, so the spans add up; a persistent-cache
-    hit skips the compile and is counted instead)."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_) -> None:
-        if event == _BACKEND_COMPILE:
-            self.seconds += secs
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
 
 
 def nbytes(tree) -> int:
@@ -315,6 +292,7 @@ def main() -> None:
         sys.exit(f"chip_smoke: JAX found no TPU (platform {dev.platform!r})")
     if args.four_chips and len(devices) != 4:
         sys.exit(f"chip_smoke: --four-chips needs 4 chips, found {len(devices)}")
+    from bench.harness import CompileClock
     from repro.launch.serve import use_compile_cache
 
     device = {"platform": dev.platform, "kind": dev.device_kind,

@@ -114,6 +114,7 @@ from __future__ import annotations
 
 import base64
 import json
+import time
 import weakref
 import zlib
 from dataclasses import dataclass, field
@@ -122,6 +123,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.serializer import deserialize_tree, serialize_tree
 from repro.models.model_api import ModelFns
@@ -172,6 +174,9 @@ class Request:
     priority: int = 0
     deadline_ms: float | None = None
     arrival_step: int = 0
+    # host clock (perf_counter_ns) at submit(), until the queue wait it
+    # starts is counted; not snapshotted (another host's clock)
+    submit_ns: int | None = None
     # preemption: committed tokens (all but the last) re-prefilled after the
     # prompt on re-admission, so a preempted stream resumes token-exactly
     resume: list[int] = field(default_factory=list)
@@ -489,7 +494,26 @@ class ServeEngine:
             # sampling fan-out (fork)
             "forks": 0,                   # children forked off live slots
             "fork_shared_pages": 0,       # full pages children share (logical)
+            # step()s that decoded on the paged path, and summed over them
+            # the slots still prefilling and the empty slots while
+            # requests queue (after the admission scan)
+            "decode_steps": 0,
+            "prefill_slot_steps": 0,
+            "blocked_slot_steps": 0,
+            # host wall clock in ns, different in every run: from a
+            # blocking readback to the next prefill chunk or decode
+            # dispatch (the engine has nothing on the chip), and from a
+            # step()'s return to the next step()'s entry (the caller's
+            # time), each while requests are pending; from submit() to a
+            # request's first prefill chunk dispatch
+            "host_gap_ns": 0,
+            "caller_ns": 0,
+            "queue_wait_ns": 0,
+            "queue_waits": 0,
         }
+        # clock marks of the two host-time counters above (None: not timing)
+        self._ready_ns: int | None = None     # end of the last readback
+        self._returned_ns: int | None = None  # last step()'s return
 
         if paged:
             self.page_size = page_size
@@ -722,57 +746,59 @@ class ServeEngine:
                priority: int = 0,
                deadline_ms: float | None = None,
                temperature: float = 0.0, seed: int = 0) -> Request:
-        extra = dict(extra or {})
-        probe = Request(-1, list(prompt), max_new_tokens, eos_id, extra)
-        allowed = ({"embeds"} if self._mm else set()) | (
-            {"frames"} if self.cross else set()
-        )
-        if self.paged and set(extra) - allowed:
-            raise ValueError(
-                f"unsupported modality extras {sorted(set(extra) - allowed)} "
-                "for this family's paged path; construct the engine with "
-                "paged=False"
+        with TraceAnnotation("engine.submit", req=self._req_counter):
+            extra = dict(extra or {})
+            probe = Request(-1, list(prompt), max_new_tokens, eos_id, extra)
+            allowed = ({"embeds"} if self._mm else set()) | (
+                {"frames"} if self.cross else set()
             )
-        if self._mm and "embeds" not in extra:
-            raise ValueError("vlm requests need extra={'embeds': ...}")
-        if self.cross and "frames" not in extra:
-            raise ValueError("enc-dec requests need extra={'frames': ...}")
-        tlen = self._total_len(probe)
-        if not 1 <= len(prompt) or not tlen < self.max_seq:
-            raise ValueError(
-                f"prompt length {len(prompt)} (+{tlen - len(prompt)} "
-                f"modality positions) outside [1, {self.max_seq})"
-            )
-        if self.paged:
-            need = pages_needed(
-                min(tlen + max_new_tokens, self.max_seq), self.page_size
-            )
-            if self.cross:
-                n_cp = pages_needed(self._n_frames(probe), self.page_size)
-                if (n_cp > self.max_cross_pages
-                        or self._n_frames(probe) > self.max_cross_seq):
-                    raise ValueError(
-                        f"{self._n_frames(probe)} frames exceed "
-                        f"max_cross_seq={self.max_cross_seq}"
-                    )
-                need += n_cp
-            if need > self.n_pages - 1:
+            if self.paged and set(extra) - allowed:
                 raise ValueError(
-                    f"request needs {need} pages but the pool only has "
-                    f"{self.n_pages - 1} allocatable pages"
+                    f"unsupported modality extras {sorted(set(extra) - allowed)} "
+                    "for this family's paged path; construct the engine with "
+                    "paged=False"
                 )
-        req = Request(self._req_counter, list(prompt), max_new_tokens, eos_id,
-                      extra, priority=priority, deadline_ms=deadline_ms,
-                      arrival_step=self.steps,
-                      temperature=temperature, seed=seed)
-        if deadline_ms is not None:
-            self._has_deadlines = True
-        self._req_counter += 1
-        self.requests[req.req_id] = req
-        self.queue.append(req)
-        if self.paged:
-            self._admit_ready = True
-        return req
+            if self._mm and "embeds" not in extra:
+                raise ValueError("vlm requests need extra={'embeds': ...}")
+            if self.cross and "frames" not in extra:
+                raise ValueError("enc-dec requests need extra={'frames': ...}")
+            tlen = self._total_len(probe)
+            if not 1 <= len(prompt) or not tlen < self.max_seq:
+                raise ValueError(
+                    f"prompt length {len(prompt)} (+{tlen - len(prompt)} "
+                    f"modality positions) outside [1, {self.max_seq})"
+                )
+            if self.paged:
+                need = pages_needed(
+                    min(tlen + max_new_tokens, self.max_seq), self.page_size
+                )
+                if self.cross:
+                    n_cp = pages_needed(self._n_frames(probe), self.page_size)
+                    if (n_cp > self.max_cross_pages
+                            or self._n_frames(probe) > self.max_cross_seq):
+                        raise ValueError(
+                            f"{self._n_frames(probe)} frames exceed "
+                            f"max_cross_seq={self.max_cross_seq}"
+                        )
+                    need += n_cp
+                if need > self.n_pages - 1:
+                    raise ValueError(
+                        f"request needs {need} pages but the pool only has "
+                        f"{self.n_pages - 1} allocatable pages"
+                    )
+            req = Request(self._req_counter, list(prompt), max_new_tokens, eos_id,
+                          extra, priority=priority, deadline_ms=deadline_ms,
+                          arrival_step=self.steps,
+                          submit_ns=time.perf_counter_ns(),
+                          temperature=temperature, seed=seed)
+            if deadline_ms is not None:
+                self._has_deadlines = True
+            self._req_counter += 1
+            self.requests[req.req_id] = req
+            self.queue.append(req)
+            if self.paged:
+                self._admit_ready = True
+            return req
 
     def pending(self) -> int:
         return len(self.queue) + sum(s is not None for s in self.slot_req)
@@ -835,10 +861,29 @@ class ServeEngine:
         waiting request outranks it — slots join and leave the decode
         batch every iteration. ``last_step_tokens`` records the step's
         decode + prefill token total for budget accounting.
+
+        Each call is an ``engine.step`` span in a profiler trace, with
+        ``engine.*`` spans for its phases inside (on the paged
+        continuous-batching path), and feeds the host-clock counters of
+        ``stats`` (``caller_ns``, ``host_gap_ns``, ``queue_wait_ns``).
         """
+        t_in = time.perf_counter_ns()
+        if self._returned_ns is not None:
+            self.stats["caller_ns"] += t_in - self._returned_ns
+        with TraceAnnotation("engine.step"):
+            n = self._step(force_tokens)
+        if self.pending():
+            self._returned_ns = time.perf_counter_ns()
+        else:
+            # an empty engine waits for work, not for the host
+            self._returned_ns = self._ready_ns = None
+        return n
+
+    def _step(self, force_tokens: dict[int, int] | None) -> int:
         if self.paged and not self.sched.cfg.synchronous:
-            self._shed_pass()
-            self._admission_scan()
+            with TraceAnnotation("engine.schedule"):
+                self._shed_pass()
+                self._admission_scan()
             lanes = [
                 i for i, r in enumerate(self.slot_req)
                 if r is not None and i not in self.prefilling
@@ -854,7 +899,8 @@ class ServeEngine:
                 self.sched.prefill_budget(len(lanes), bool(self.prefilling),
                                           tokens_per_lane=per_lane)
             )
-            self._preempt_pass()
+            with TraceAnnotation("engine.schedule"):
+                self._preempt_pass()
         else:
             prefill_used = self._admit()
         if self.paged:
@@ -869,6 +915,10 @@ class ServeEngine:
                     self.steps += 1
                 self.last_step_tokens = prefill_used
                 return 0
+            self.stats["decode_steps"] += 1
+            self.stats["prefill_slot_steps"] += len(self.prefilling)
+            if self.queue:
+                self.stats["blocked_slot_steps"] += self.slot_req.count(None)
         else:
             active = [i for i, r in enumerate(self.slot_req)
                       if r is not None]
@@ -879,56 +929,79 @@ class ServeEngine:
             # speculative rounds complete within one step(): spec holds no
             # cross-step state, so snapshot/preempt/cancel never see a
             # half-verified draft
+            self._dispatching()
             self._spec_step(active)
             self.steps += 1
             self.last_step_tokens = (
                 prefill_used + len(active) * self._spec_tokens_per_lane()
             )
             return len(active)
-        tokens = jnp.asarray(self.last_token)[:, None]
-        positions = jnp.asarray(self.lengths)
-        if self.paged:
-            batch = {
-                "tokens": tokens,
-                "positions": positions,
-                "page_table": jnp.asarray(self.page_table),
-            }
-            if self.cross:
-                batch["cross_page_table"] = jnp.asarray(self.cross_table)
-                batch["cross_len"] = jnp.asarray(self.cross_len)
-            logits, self.cache = self._decode_paged(self.params, self.cache,
-                                                    batch)
-            if self._draft is not None:
-                # keep the draft cache position-complete through
-                # non-speculative steps (forced replay, budget fallback):
-                # draft K/V holes would only degrade later proposals, but
-                # there is no reason to accept the degradation
-                _, self.cache = self._draft_decode(self.draft_params,
-                                                   self.cache, batch)
-        else:
-            logits, self.cache = self._decode(
-                self.params, self.cache,
-                {"tokens": tokens, "positions": positions},
-            )
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        rows = (np.asarray(logits, np.float32)
-                if self._any_sampled(active) else None)
-        for i in active:
-            req = self.requests[self.slot_req[i]]
-            if rows is not None and req.temperature > 0:
-                tok = self._choose(rows[i], req, int(self.lengths[i]))
+        with TraceAnnotation("engine.upload"):
+            tokens = jnp.asarray(self.last_token)[:, None]
+            positions = jnp.asarray(self.lengths)
+            if self.paged:
+                batch = {
+                    "tokens": tokens,
+                    "positions": positions,
+                    "page_table": jnp.asarray(self.page_table),
+                }
+                if self.cross:
+                    batch["cross_page_table"] = jnp.asarray(self.cross_table)
+                    batch["cross_len"] = jnp.asarray(self.cross_len)
+        with TraceAnnotation("engine.decode", lanes=len(active)):
+            self._dispatching()
+            if self.paged:
+                logits, self.cache = self._decode_paged(self.params,
+                                                        self.cache, batch)
+                if self._draft is not None:
+                    # keep the draft cache position-complete through
+                    # non-speculative steps (forced replay, budget
+                    # fallback): draft K/V holes would only degrade later
+                    # proposals, but there is no reason to accept that
+                    _, self.cache = self._draft_decode(self.draft_params,
+                                                       self.cache, batch)
             else:
-                tok = int(next_tokens[i])
-            if force_tokens is not None and req.req_id in force_tokens:
-                forced = int(force_tokens[req.req_id])
-                self.stats["forced_tokens"] += 1
-                if forced != tok:
-                    self.stats["forced_mismatches"] += 1
-                tok = forced
-            self._commit_token(i, req, tok)
+                logits, self.cache = self._decode(
+                    self.params, self.cache,
+                    {"tokens": tokens, "positions": positions},
+                )
+        with TraceAnnotation("engine.readback"):
+            next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            rows = (np.asarray(logits, np.float32)
+                    if self._any_sampled(active) else None)
+            self._ready_ns = time.perf_counter_ns()
+        with TraceAnnotation("engine.commit"):
+            for i in active:
+                req = self.requests[self.slot_req[i]]
+                if rows is not None and req.temperature > 0:
+                    tok = self._choose(rows[i], req, int(self.lengths[i]))
+                else:
+                    tok = int(next_tokens[i])
+                if force_tokens is not None and req.req_id in force_tokens:
+                    forced = int(force_tokens[req.req_id])
+                    self.stats["forced_tokens"] += 1
+                    if forced != tok:
+                        self.stats["forced_mismatches"] += 1
+                    tok = forced
+                self._commit_token(i, req, tok)
         self.steps += 1
         self.last_step_tokens = prefill_used + len(active)
         return len(active)
+
+    def _dispatching(self) -> None:
+        """A prefill chunk or a decode is about to be queued on the chip:
+        the host gap since the engine's last blocking readback ends."""
+        if self._ready_ns is not None:
+            self.stats["host_gap_ns"] += time.perf_counter_ns() - self._ready_ns
+            self._ready_ns = None
+
+    def _queue_wait_ends(self, req: Request) -> None:
+        """``req``'s prefill begins: count its wait since ``submit()``
+        (once: a preempted request re-prefilling does not count again)."""
+        if req.submit_ns is not None:
+            self.stats["queue_wait_ns"] += time.perf_counter_ns() - req.submit_ns
+            self.stats["queue_waits"] += 1
+            req.submit_ns = None
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         while self.pending() and max_steps > 0:
@@ -1895,14 +1968,20 @@ class ServeEngine:
             if self.cross:
                 batch["cross_page_table"] = jnp.asarray(self.cross_table)
                 batch["cross_len"] = jnp.asarray(self.cross_len)
+            self._dispatching()
+            self._queue_wait_ends(req)
             logits, self.cache = self._decode_paged(self.params, self.cache,
                                                     batch)
             if self._draft is not None:
                 # the recomputed final prompt token needs its draft K/V too
                 _, self.cache = self._draft_decode(self.draft_params,
                                                    self.cache, batch)
-            first = int(np.asarray(jnp.argmax(logits[slot])))
-            self._finish_prefill(slot, req, key_tokens, chain, first, tlen)
+            with TraceAnnotation("engine.first_token", req=req.req_id,
+                                 slot=slot):
+                first = int(np.asarray(jnp.argmax(logits[slot])))
+                self._ready_ns = time.perf_counter_ns()
+                self._finish_prefill(slot, req, key_tokens, chain, first,
+                                     tlen)
             return
         embeds = (
             np.asarray(req.extra["embeds"]).reshape(mm, -1) if mm else None
@@ -1929,54 +2008,61 @@ class ServeEngine:
         row[: len(chain)] = chain
         table_row = jnp.asarray(row)
         used = 0
+        rid = task.req.req_id
         while task.offset < task.tlen:
             n = min(C, task.tlen - task.offset)
             if (budget is not None and n > budget - used
                     and not (force and used == 0)):
                 break
             off = task.offset
-            si = min(max(mm - off, 0), n)  # image rows in this chunk
-            toks = np.zeros((1, C), np.int32)
-            if si < n:
-                toks[0, si:n] = task.ptoks[off + si - mm: off + n - mm]
-            batch = {
-                "tokens": jnp.asarray(toks),
-                "valid": jnp.asarray(n, jnp.int32),
-                "slot": jnp.asarray(slot, jnp.int32),
-                "page_table": table_row,
-            }
-            kw: dict[str, int] = {"offset": off}
-            if self._mm:
-                emb = np.zeros((1, C, task.embeds.shape[1]),
-                               task.embeds.dtype)
-                if si:
-                    emb[0, :si] = task.embeds[off:off + si]
-                batch["embeds"] = jnp.asarray(emb)
-                kw["mm_len"] = mm
-            if self.cross:
-                batch["cross_page_table"] = jnp.asarray(
-                    self.cross_table[slot]
+            with TraceAnnotation("engine.prefill_chunk", req=rid, slot=slot,
+                                 offset=off, valid=n):
+                si = min(max(mm - off, 0), n)  # image rows in this chunk
+                toks = np.zeros((1, C), np.int32)
+                if si < n:
+                    toks[0, si:n] = task.ptoks[off + si - mm: off + n - mm]
+                batch = {
+                    "tokens": jnp.asarray(toks),
+                    "valid": jnp.asarray(n, jnp.int32),
+                    "slot": jnp.asarray(slot, jnp.int32),
+                    "page_table": table_row,
+                }
+                kw: dict[str, int] = {"offset": off}
+                if self._mm:
+                    emb = np.zeros((1, C, task.embeds.shape[1]),
+                                   task.embeds.dtype)
+                    if si:
+                        emb[0, :si] = task.embeds[off:off + si]
+                    batch["embeds"] = jnp.asarray(emb)
+                    kw["mm_len"] = mm
+                if self.cross:
+                    batch["cross_page_table"] = jnp.asarray(
+                        self.cross_table[slot]
+                    )
+                    batch["cross_len"] = jnp.asarray(self.cross_len[slot],
+                                                     jnp.int32)
+                self._dispatching()
+                self._queue_wait_ends(task.req)
+                task.logits, self.cache = self._prefill_chunk(
+                    self.params, self.cache, batch, **kw
                 )
-                batch["cross_len"] = jnp.asarray(self.cross_len[slot],
-                                                 jnp.int32)
-            task.logits, self.cache = self._prefill_chunk(
-                self.params, self.cache, batch, **kw
-            )
-            if self._draft is not None:
-                # the draft rides every prefill chunk: its K/V for the
-                # prompt lands in the same pages, so shared/COW'd prefixes
-                # arrive draft-complete (batch is identical — draft
-                # families are text-only, no mm/cross extras)
-                self.cache = self._draft_prefill(self.draft_params,
-                                                 self.cache, batch,
-                                                 offset=off)
+                if self._draft is not None:
+                    # the draft rides every prefill chunk: its K/V for the
+                    # prompt lands in the same pages, so shared/COW'd
+                    # prefixes arrive draft-complete (batch is identical —
+                    # draft families are text-only, no mm/cross extras)
+                    self.cache = self._draft_prefill(self.draft_params,
+                                                     self.cache, batch,
+                                                     offset=off)
             task.offset += n
             used += n
         if task.offset >= task.tlen:
-            first = int(np.asarray(jnp.argmax(task.logits, axis=-1))[0])
-            del self.prefilling[slot]
-            self._finish_prefill(slot, task.req, task.key_tokens,
-                                 chain, first, task.tlen)
+            with TraceAnnotation("engine.first_token", req=rid, slot=slot):
+                first = int(np.asarray(jnp.argmax(task.logits, axis=-1))[0])
+                self._ready_ns = time.perf_counter_ns()
+                del self.prefilling[slot]
+                self._finish_prefill(slot, task.req, task.key_tokens,
+                                     chain, first, task.tlen)
         self._step_prefill_tokens += used
         return used
 
@@ -2047,6 +2133,8 @@ class ServeEngine:
         batch = {"tokens": jnp.asarray(toks)}
         for k, v in req.extra.items():
             batch[k] = jnp.asarray(v)
+        self._dispatching()
+        self._queue_wait_ends(req)
         logits, pcache = self._prefill(self.params, batch)
         # left-padding means cache rows [0, bucket-plen) belong to pad
         # tokens; with causal attention + right-aligned queries they are
@@ -2242,6 +2330,7 @@ class ServeEngine:
                         self._evict_node(sid)
             self.prefilling = {}      # snapshots drain in-flight prefills
             self._admit_ready = True  # restored queue must be rescanned
+        self._ready_ns = self._returned_ns = None
         self.stats = {**self.stats,
                       **{k: int(v) for k, v in meta.get("stats", {}).items()}}
         self.requests = {}
