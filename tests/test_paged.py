@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import REDUCED
-from repro.kernels import ops, ref
+from repro.kernels import ops, paged_decode_attention, ref
 from repro.models import get_model
 from repro.serving.engine import ServeEngine
 from repro.serving.kvcache import PagePool, pages_needed
@@ -80,27 +80,51 @@ def test_pages_needed():
 # ---------------------------------------------------------------------------
 
 
-def _paged_case(b, h, k, d, page, max_pages, n_pages, dtype):
+def _paged_case(b, h, k, d, page, max_pages, n_pages, dtype, lens=None):
     q = jnp.asarray(RNG.standard_normal((b, h, d)), dtype)
     kp = jnp.asarray(RNG.standard_normal((n_pages, page, k, d)), dtype)
     vp = jnp.asarray(RNG.standard_normal((n_pages, page, k, d)), dtype)
     ids = RNG.permutation(np.arange(1, n_pages))[: b * max_pages]
-    table = jnp.asarray(ids.reshape(b, max_pages), jnp.int32)
-    lens = jnp.asarray(RNG.integers(1, max_pages * page + 1, b), jnp.int32)
-    return q, kp, vp, table, lens
+    table = ids.reshape(b, max_pages)
+    if lens is None:
+        lens = RNG.integers(1, max_pages * page + 1, b)
+    else:
+        # as the engine leaves it: entries past a lane's chain hold page 0
+        chain = -(-np.asarray(lens) // page)
+        table = np.where(np.arange(max_pages) < chain[:, None], table, 0)
+    return (q, kp, vp, jnp.asarray(table, jnp.int32),
+            jnp.asarray(lens, jnp.int32))
+
+
+def _block_tokens(page, max_pages, k, d, dtype):
+    """Tokens per block of the Pallas kernel at these shapes."""
+    return page * paged_decode_attention.pages_per_block(
+        page, max_pages, k, d, jnp.dtype(dtype).itemsize)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
 @pytest.mark.parametrize(
-    "b,h,k,d,page,max_pages,n_pages",
-    [(2, 4, 2, 16, 8, 4, 12), (3, 8, 8, 32, 16, 3, 16),
-     (1, 16, 2, 64, 8, 5, 8)],
+    "b,h,k,d,page,max_pages,n_pages,edges",
+    [pytest.param(2, 4, 2, 16, 8, 4, 12, False, id="2-4-2-16-8-4-12"),
+     pytest.param(3, 8, 8, 32, 16, 3, 16, False, id="3-8-8-32-16-3-16"),
+     pytest.param(1, 16, 2, 64, 8, 5, 8, False, id="1-16-2-64-8-5-8"),
+     # GQA groups of 3 and 4 over 64-token pages and tables of 10 pages,
+     # not a whole number of blocks: lanes one below, at and one above a
+     # block boundary, inside the first page, and the whole table
+     pytest.param(6, 6, 2, 16, 64, 10, 64, True, id="gqa3-edges"),
+     pytest.param(6, 8, 2, 16, 64, 10, 64, True, id="gqa4-edges")],
 )
-def test_paged_decode_attention(b, h, k, d, page, max_pages, n_pages,
+def test_paged_decode_attention(b, h, k, d, page, max_pages, n_pages, edges,
                                 backend, dtype):
+    lens = None
+    if edges:
+        block = _block_tokens(page, max_pages, k, d, dtype)
+        assert max_pages * page > block and max_pages % (block // page)
+        lens = [block - 1, block, block + 1, 1, page // 2,
+                max_pages * page]
     q, kp, vp, table, lens = _paged_case(b, h, k, d, page, max_pages,
-                                         n_pages, dtype)
+                                         n_pages, dtype, lens)
     want = ref.paged_decode_attention(q, kp, vp, table, lens)
     with ops.use_backend(backend):
         got = ops.paged_decode_attention(q, kp, vp, table, lens)
@@ -118,6 +142,18 @@ def test_paged_decode_attention_zero_length_lane():
         got = ops.paged_decode_attention(q, kp, vp, table, lens)
     assert np.allclose(np.asarray(got)[0], 0.0)
     assert not np.any(np.isnan(np.asarray(got)))
+    # empty lanes beside full ones over several blocks: a full lane's last
+    # block starts the copy of the next lane with work, past the empty ones
+    full = 10 * 64
+    q, kp, vp, table, lens = _paged_case(
+        6, 6, 2, 16, 64, 10, 64, jnp.float32, [0, full, 0, 0, full, 1])
+    with ops.use_backend("pallas_interpret"):
+        got = np.asarray(ops.paged_decode_attention(q, kp, vp, table, lens))
+    want = np.asarray(ref.paged_decode_attention(q, kp, vp, table, lens))
+    assert not np.any(np.isnan(got))
+    np.testing.assert_array_equal(got[[0, 2, 3]], 0.0)
+    np.testing.assert_allclose(got[[1, 4, 5]], want[[1, 4, 5]],
+                               atol=2e-5, rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,8 @@ TPU attached): the TPU compiler refuses what interpret mode accepts —
 blocks not aligned to the (8, 128) tiling, more VMEM than a kernel may
 scope, a program that does not fit the chip's 16 GB. Shapes are the
 published widths of the models that use each kernel: phi4-mini-3.8b
-(attention, RMSNorm and the whole serving step), zamba2-1.2b (SSD),
+(attention, RMSNorm and the whole serving step), qwen3-8b (paged decode
+attention over long contexts), zamba2-1.2b (SSD),
 falcon-mamba-7b (selective scan).
 
 The topology is described inside a fixture, never at import: only one
@@ -25,6 +26,8 @@ from repro.kernels import paged_decode_attention, rmsnorm, selective_scan, ssd
 from repro.models import get_model
 
 PHI4 = dict(B=8, H=24, K=8, D=128, d=3072, page=64, max_pages=32)
+# the decode batch of the qwen3-8b document cell: 8 slots of 8192 tokens
+QWEN3 = dict(B=8, H=32, K=8, D=128, page=64, max_pages=128, pool=321)
 V5E_HBM_BYTES = 16e9
 
 
@@ -66,14 +69,16 @@ def _compile(fn, *args):
 
 
 def test_paged_decode_attention(one_chip):
-    B, H, K, D, page, mp = (PHI4[k] for k in
-                            ("B", "H", "K", "D", "page", "max_pages"))
-    pool = _spec(one_chip, (B * mp + 1, page, K, D))
-    _compile(
-        paged_decode_attention.paged_decode_attention,
-        _spec(one_chip, (B, H, D)), pool, pool,
-        _spec(one_chip, (B, mp), jnp.int32), _spec(one_chip, (B,), jnp.int32),
-    )
+    for shape in (dict(PHI4, pool=PHI4["B"] * PHI4["max_pages"] + 1), QWEN3):
+        B, H, K, D, page, mp, n = (shape[k] for k in (
+            "B", "H", "K", "D", "page", "max_pages", "pool"))
+        pool = _spec(one_chip, (n, page, K, D))
+        _compile(
+            paged_decode_attention.paged_decode_attention,
+            _spec(one_chip, (B, H, D)), pool, pool,
+            _spec(one_chip, (B, mp), jnp.int32),
+            _spec(one_chip, (B,), jnp.int32),
+        )
 
 
 @pytest.mark.parametrize(
