@@ -36,10 +36,18 @@ class ModelConfig:
     d_head: int = 0  # 0 -> d_model // n_heads
 
     # Attention details
+    attn_kind: str = "gqa"          # gqa | mla (multi-head latent attention)
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
     causal: bool = True
+
+    # MLA (DeepSeek-V2/V3 latent attention, no q-LoRA): the cache holds one
+    # latent row per token, [c (kv_lora_rank) | k_pe (qk_rope_head_dim)]
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MLP
     gated_mlp: bool = True  # SwiGLU if True, GELU MLP if False
@@ -53,6 +61,15 @@ class ModelConfig:
     first_k_dense: int = 0          # leading dense layers (deepseek-moe style)
     d_ff_dense: int = 0             # FFN width of those dense layers
     router_aux_coef: float = 0.01
+    # softmax: top-k of softmax(logits); sigmoid_bias: top-k of
+    # sigmoid(logits) + a per-expert correction bias, which picks but
+    # never weighs (DeepSeek-V3 noaux_tc)
+    router_kind: str = "softmax"
+    routed_scale: float = 1.0       # routed_scaling_factor
+    # the chip's expert share: experts [expert_offset, expert_offset +
+    # experts_held) of the n_experts the router scores (0 = all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # SSM (Mamba)
     ssm_state: int = 0
@@ -90,11 +107,30 @@ class ModelConfig:
         if self.d_head == 0 and self.n_heads > 0:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
         assert self.family in FAMILIES, self.family
+        assert self.attn_kind in ("gqa", "mla"), self.attn_kind
+        assert self.router_kind in ("softmax", "sigmoid_bias"), self.router_kind
+        assert self.expert_offset + self.n_held <= self.n_experts, (
+            f"{self.arch_id}: experts [{self.expert_offset}, "
+            f"{self.expert_offset + self.n_held}) of {self.n_experts}")
         if self.n_heads:
             assert self.n_heads % max(self.n_kv_heads, 1) == 0, (
                 f"{self.arch_id}: n_heads={self.n_heads} not a multiple of "
                 f"n_kv_heads={self.n_kv_heads}"
             )
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def mla(self) -> bool:
+        return self.attn_kind == "mla"
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of an MLA cache row: the latent c and the shared k_pe."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def d_inner(self) -> int:
@@ -131,7 +167,15 @@ class ModelConfig:
         emb = self.vocab_size * d
         unemb = 0 if self.tie_embeddings else self.vocab_size * d
         per_layer_attn = 0.0
-        if self.uses_attention:
+        if self.mla:
+            H, r = self.n_heads, self.kv_lora_rank
+            per_layer_attn = (
+                d * H * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                + d * self.latent_dim
+                + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                + H * self.v_head_dim * d
+            )
+        elif self.uses_attention:
             qkv = d * (self.n_heads + 2 * self.n_kv_heads) * self.d_head
             out = self.n_heads * self.d_head * d
             per_layer_attn = qkv + out
@@ -175,8 +219,9 @@ class ModelConfig:
             dense_layers = self.first_k_dense
             moe_layers = L - dense_layers
             router = self.n_experts * d
-            experts_total = self.n_experts * mlp_params(self.d_expert)
-            experts_active = self.moe_top_k * mlp_params(self.d_expert)
+            experts_total = self.n_held * mlp_params(self.d_expert)
+            experts_active = (self.moe_top_k * self.n_held / self.n_experts
+                              * mlp_params(self.d_expert))
             shared = self.n_shared_experts * mlp_params(self.d_expert)
             dense_ff = mlp_params(self.d_ff_dense or self.d_ff)
             total += moe_layers * (per_layer_attn + router + experts_total + shared)

@@ -19,6 +19,7 @@ from repro.configs import (
     deepseek_moe_16b,
     zamba2_1_2b,
     whisper_medium,
+    moonlight_16b_a3b,
 )
 
 _MODULES = [
@@ -32,6 +33,7 @@ _MODULES = [
     deepseek_moe_16b,
     zamba2_1_2b,
     whisper_medium,
+    moonlight_16b_a3b,
 ]
 
 ARCHS: dict[str, ModelConfig] = {m.CONFIG.arch_id: m.CONFIG for m in _MODULES}

@@ -87,7 +87,7 @@ def _decode_kernel(
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret", "scale"))
 def decode_attention(
     q: jax.Array,        # (B, H, D)
     k: jax.Array,        # (B, S, K, D)
@@ -96,13 +96,14 @@ def decode_attention(
     *,
     block_k: int = 128,
     interpret: bool = False,
+    scale: float | None = None,  # of the scores; None: D**-0.5
 ) -> jax.Array:
     # block_k 128: at 256 the f32 working copies of an 8-kv-head tile
     # overflow v5e's 16 MiB scoped VMEM (phi4-mini widths)
     B, H, D = q.shape
     S, K = k.shape[1], k.shape[2]
     assert H % K == 0, (H, K)
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     bk = min(block_k, S)
     pk = (-S) % bk

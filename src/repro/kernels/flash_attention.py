@@ -10,7 +10,9 @@ win over the XLA fallback.
 
 Causal blocks that are entirely masked are skipped with ``pl.when``
 (their flops never execute — the kernel does ~half the work of the dense
-score matrix). Block shapes default to 512×512 tiles of ``(seq, head_dim)``
+score matrix). V's head size may differ from Q's and K's (latent attention
+expands 192-wide keys beside 128-wide values). Block shapes default to
+512×512 tiles of ``(seq, head_dim)``
 — MXU-aligned (128 multiples) and ≤ ~4 MiB of VMEM at f32 for d ≤ 256.
 """
 
@@ -29,11 +31,11 @@ NEG_INF = -1e30
 def _flash_kernel(
     q_ref,    # (1, 1, bq, d)
     k_ref,    # (1, 1, bk, d)
-    v_ref,    # (1, 1, bk, d)
-    o_ref,    # (1, 1, bq, d)
+    v_ref,    # (1, 1, bk, dv)
+    o_ref,    # (1, 1, bq, dv)
     m_ref,    # scratch (bq,)
     l_ref,    # scratch (bq,)
-    acc_ref,  # scratch (bq, d)
+    acc_ref,  # scratch (bq, dv)
     *,
     causal: bool,
     q_offset: int,
@@ -96,7 +98,7 @@ def _flash_kernel(
 def flash_attention(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Sk, K, D)
-    v: jax.Array,  # (B, Sk, K, D)
+    v: jax.Array,  # (B, Sk, K, Dv)
     *,
     causal: bool = True,
     q_offset: int = 0,
@@ -105,7 +107,7 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, Sq, H, D = q.shape
-    Sk, K = k.shape[1], k.shape[2]
+    Sk, K, Dv = k.shape[1], k.shape[2], v.shape[-1]
     assert H % K == 0, (H, K)
     q_per_kv = H // K
     scale = D ** -0.5
@@ -146,16 +148,17 @@ def flash_attention(
                 lambda b, h, qi, ki: (b, h // q_per_kv, ki, 0),
             ),
             pl.BlockSpec(
-                (1, 1, bk, D),
+                (1, 1, bk, Dv),
                 lambda b, h, qi, ki: (b, h // q_per_kv, ki, 0),
             ),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, Dv),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)

@@ -138,13 +138,15 @@ def _expand_kv(k: jax.Array, n_heads: int) -> jax.Array:
 def attention(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Sk, K, D)
-    v: jax.Array,  # (B, Sk, K, D)
+    v: jax.Array,  # (B, Sk, K, Dv)
     *,
     causal: bool = True,
     q_offset: int = 0,
     block_q: int = 512,
     block_k: int = 512,
 ) -> jax.Array:
+    """Blocked causal (or full) GQA attention -> (B, Sq, H, Dv), scores
+    scaled by ``D**-0.5``."""
     b = current_backend()
     if b == "xla":
         return _flash_attention_xla(
@@ -167,9 +169,10 @@ def _flash_attention_xla(q, k, v, *, causal, q_offset, block_q, block_k):
 
     vmapped over query blocks, lax.scan over key/value blocks; f32 softmax
     statistics; memory per device is O(block_q * block_k) per (batch, head).
+    v's head size may differ from q's and k's.
     """
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     k = _expand_kv(k, H)
     v = _expand_kv(v, H)
     scale = D ** -0.5
@@ -216,14 +219,14 @@ def _flash_attention_xla(q, k, v, *, causal, q_offset, block_q, block_k):
 
         m0 = jnp.full((B, H, bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, bq), jnp.float32)
-        a0 = jnp.zeros((B, H, bq, D), jnp.float32)
+        a0 = jnp.zeros((B, H, bq, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(nk),
                                       unroll=tracing.scan_unroll())
         out = acc / jnp.maximum(l, 1e-30)[..., None]         # (B,H,bq,D)
         return out.transpose(0, 2, 1, 3)                      # (B,bq,H,D)
 
     out = jax.vmap(per_q_block, in_axes=(0, 0), out_axes=0)(jnp.arange(nq), qb)
-    out = out.transpose(1, 0, 2, 3, 4).reshape(B, Sq + pq, H, D)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(B, Sq + pq, H, Dv)
     return out[:, :Sq].astype(q.dtype)
 
 
@@ -237,24 +240,27 @@ def decode_attention(
     k: jax.Array,        # (B, S, K, D)
     v: jax.Array,        # (B, S, K, D)
     lengths: jax.Array,  # (B,) int32
+    *,
+    scale: float | None = None,   # of the scores; None: D**-0.5
 ) -> jax.Array:
     b = current_backend()
     if b == "xla":
-        return _decode_attention_xla(q, k, v, lengths)
+        return _decode_attention_xla(q, k, v, lengths, scale=scale)
     mod = _pallas("decode_attention")
     kernel = functools.partial(mod.decode_attention,
-                               interpret=(b == "pallas_interpret"))
+                               interpret=(b == "pallas_interpret"),
+                               scale=scale)
     bt, m = _kernel_axes(q.shape[0], q.shape[1], k.shape[2])
     kv = P(bt, None, m, None)
     return _per_shard(kernel, (q, k, v, lengths),
                       (P(bt, m, None), kv, kv, P(bt)), P(bt, m, None))
 
 
-def _decode_attention_xla(q, k, v, lengths):
+def _decode_attention_xla(q, k, v, lengths, *, scale=None):
     B, H, D = q.shape
     S, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     qg = q.reshape(B, K, G, D).astype(jnp.float32) * scale
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32))
     mask = jnp.arange(S)[None, :] < lengths[:, None]          # (B,S)
@@ -278,24 +284,27 @@ def paged_decode_attention(
     v_pages: jax.Array,     # (n_pages, P, K, D)
     page_table: jax.Array,  # (B, max_pages) int32
     lengths: jax.Array,     # (B,) int32
+    *,
+    scale: float | None = None,   # of the scores; None: D**-0.5
 ) -> jax.Array:
     b = current_backend()
     if b == "xla":
         return _paged_decode_attention_xla(q, k_pages, v_pages, page_table,
-                                           lengths)
+                                           lengths, scale=scale)
     return _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths,
-                                interpret=(b == "pallas_interpret"))
+                                interpret=(b == "pallas_interpret"),
+                                scale=scale)
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
-                         interpret):
+                         interpret, scale=None):
     """The paged flash-decode kernel, split per shard under a mesh: lanes
     over the data axes, query heads and the pool's kv heads over
     ``model`` (page ids stay global, so the pool's page dim never
     splits)."""
     mod = _pallas("paged_decode_attention")
     kernel = functools.partial(mod.paged_decode_attention,
-                               interpret=interpret)
+                               interpret=interpret, scale=scale)
     bt, m = _kernel_axes(q.shape[0], q.shape[1], k_pages.shape[2])
     pool = P(None, None, m, None)
     return _per_shard(kernel, (q, k_pages, v_pages, page_table, lengths),
@@ -303,7 +312,8 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
                       P(bt, m, None))
 
 
-def _paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths):
+def _paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths, *,
+                                scale=None):
     """Pure-XLA paged decode: gather each sequence's pages through the same
     page table the Pallas kernel prefetches, then run the masked-softmax
     decode path. The gather is a transient — the resident cache stays paged."""
@@ -311,7 +321,7 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths):
     K = k_pages.shape[2]
     k = k_pages[page_table].reshape(B, -1, K, D)
     v = v_pages[page_table].reshape(B, -1, K, D)
-    return _decode_attention_xla(q, k, v, lengths)
+    return _decode_attention_xla(q, k, v, lengths, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -430,6 +440,80 @@ def _paged_cross_attention_xla(q, k_pages, v_pages, page_table, lengths):
     out = jnp.einsum("bhqk,bkhd->bqhd", p / jnp.maximum(l, 1e-30),
                      v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmul over held experts (dropless MoE)
+# ---------------------------------------------------------------------------
+
+
+def moe_experts(lhs: jax.Array, wg: jax.Array, wu: jax.Array,
+                wd: jax.Array, group_sizes: jax.Array,
+                layer: jax.Array | None = None) -> jax.Array:
+    """Each row's expert MLP, ``(silu(x @ wg[g]) * (x @ wu[g])) @ wd[g]``,
+    for the rows of ``lhs (M, d)`` sorted by group (``group_sizes (G,)``)
+    -> (M, d) in ``lhs``'s dtype, f32 inside; rows past the last group are
+    zero. The weights are ``(G, ., .)`` or layer stacks ``(L, G, ., .)``
+    with ``layer`` their index, read in place.
+
+    Under a mesh whose ``model`` axis divides G, the experts split over
+    ``model`` as the weights are laid out: each shard runs its own groups'
+    rows through its own experts, its other rows zero, and the shards'
+    outputs are summed, so no shard gathers another's expert weights."""
+    mesh = partition.current_mesh()
+    G = wg.shape[-3]
+    if (mesh is None or "model" not in mesh.axis_names
+            or G % mesh.shape["model"]):
+        return _experts(lhs, wg, wu, wd, group_sizes, layer)
+    stacked = layer is not None
+
+    def body(lhs, wg, wu, wd, sizes, layer):
+        n = wg.shape[-3]
+        first = jax.lax.axis_index("model") * n
+        start = jnp.sum(jnp.where(jnp.arange(G) < first, sizes, 0))
+        local = jax.lax.dynamic_slice_in_dim(sizes, first, n)
+        out = _experts(jnp.roll(lhs, -start, 0), wg, wu, wd, local,
+                       layer if stacked else None)
+        return jax.lax.psum(jnp.roll(out, start, 0), "model")
+
+    w = P(*(None,) * (wg.ndim - 3), "model", None, None)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), w, w, w, P(), P()),
+                         out_specs=P(), check_vma=False)(
+        lhs, wg, wu, wd, group_sizes, _layer_arg(layer))
+
+
+def _experts(lhs, wg, wu, wd, group_sizes, layer):
+    """:func:`moe_experts` on one device: the grouped matmul kernels
+    (``moe_gmm_swiglu``, then ``moe_gmm``), or ``jax.lax.ragged_dot`` as
+    their XLA twin."""
+    if current_backend() == "xla":
+        g, u = (_ragged_dot(lhs, _layer_of(w, layer), group_sizes)
+                for w in (wg, wu))
+        h = (g * jax.nn.sigmoid(g) * u).astype(lhs.dtype)
+        return _ragged_dot(h, _layer_of(wd, layer), group_sizes
+                           ).astype(lhs.dtype)
+    mod = _pallas("moe_gmm")
+    interpret = current_backend() == "pallas_interpret"
+    h = mod.moe_gmm_swiglu(lhs, wg, wu, group_sizes, layer,
+                           interpret=interpret)
+    out = mod.moe_gmm(h, wd, group_sizes, layer, interpret=interpret)
+    # the kernels leave rows of no group unwritten: select, never multiply
+    rows = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(rows[:, None], out, jnp.zeros((), out.dtype))
+
+
+def _layer_of(w: jax.Array, layer) -> jax.Array:
+    return w if layer is None else jax.lax.dynamic_index_in_dim(
+        w, layer, 0, keepdims=False)
+
+
+def _layer_arg(layer) -> jax.Array:
+    return jnp.asarray(0 if layer is None else layer, jnp.int32)
+
+
+def _ragged_dot(lhs, rhs, group_sizes):
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

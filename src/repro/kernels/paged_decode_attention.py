@@ -22,8 +22,8 @@ A page's rows are (token, kv head) pairs, so a chunk is a plain
 ``(tokens * K, D)`` matrix: one score matmul of all H query heads against
 it, with the pairs of another kv head masked out (GQA without head
 expansion in HBM), and one value matmul. q, K and V enter the MXU in their
-stored dtype with f32 accumulation; the ``D**-0.5`` scale applies to the f32
-scores; the online-softmax statistics and the accumulator are f32 and carry
+stored dtype with f32 accumulation; the scale (``D**-0.5`` unless given)
+applies to the f32 scores; the online-softmax statistics and the accumulator are f32 and carry
 in VMEM across the lane's blocks; the probabilities stay f32 for the value
 matmul.
 """
@@ -203,7 +203,7 @@ def _paged_decode_kernel(
                 ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def paged_decode_attention(
     q: jax.Array,           # (B, H, D)
     k_pages: jax.Array,     # (n_pages, P, K, D)
@@ -212,6 +212,7 @@ def paged_decode_attention(
     lengths: jax.Array,     # (B,) int32 — valid tokens per sequence
     *,
     interpret: bool = False,
+    scale: float | None = None,  # of the scores; None: D**-0.5
 ) -> jax.Array:
     B, H, D = q.shape
     n_pages, P, K, _ = k_pages.shape
@@ -225,7 +226,8 @@ def paged_decode_attention(
                      0.0, NEG_INF).astype(jnp.float32)
 
     kernel = functools.partial(_paged_decode_kernel, page_size=P, k_heads=K,
-                               max_pages=max_pages, scale=D ** -0.5)
+                               max_pages=max_pages,
+                               scale=D ** -0.5 if scale is None else scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),

@@ -500,6 +500,8 @@ class ServeEngine:
             "decode_steps": 0,
             "prefill_slot_steps": 0,
             "blocked_slot_steps": 0,
+            # pool pages that live requests hold, summed over those steps
+            "live_page_steps": 0,
             # host wall clock in ns, different in every run: from a
             # blocking readback to the next prefill chunk or decode
             # dispatch (the engine has nothing on the chip), and from a
@@ -514,6 +516,10 @@ class ServeEngine:
         # clock marks of the two host-time counters above (None: not timing)
         self._ready_ns: int | None = None     # end of the last readback
         self._returned_ns: int | None = None  # last step()'s return
+        # the model's device counters (``stat_*`` leaves of a paged cache)
+        # and their values at the last read (:meth:`_read_counters`)
+        self._counters: list[str] = []
+        self._counted: dict[str, int] = {}
 
         if paged:
             self.page_size = page_size
@@ -569,6 +575,10 @@ class ServeEngine:
             self.slot_hold = np.zeros((n_slots,), np.int32)
             self.cache = init_paged_cache(model, n_slots, self.n_pages,
                                           page_size, cache_dtype)
+            self._counters = sorted(k for k in self.cache
+                                    if k.startswith("stat_"))
+            self._counted = dict.fromkeys(self._counters, 0)
+            self.stats.update((k[5:], 0) for k in self._counters)
             if draft is not None:
                 # same n_slots / n_pages / page_size: physical page ids in
                 # the target's page tables address the draft leaves too
@@ -917,6 +927,7 @@ class ServeEngine:
                 return 0
             self.stats["decode_steps"] += 1
             self.stats["prefill_slot_steps"] += len(self.prefilling)
+            self.stats["live_page_steps"] += self.pool.outstanding
             if self.queue:
                 self.stats["blocked_slot_steps"] += self.slot_req.count(None)
         else:
@@ -969,6 +980,7 @@ class ServeEngine:
             next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             rows = (np.asarray(logits, np.float32)
                     if self._any_sampled(active) else None)
+            self._read_counters()
             self._ready_ns = time.perf_counter_ns()
         with TraceAnnotation("engine.commit"):
             for i in active:
@@ -987,6 +999,19 @@ class ServeEngine:
         self.steps += 1
         self.last_step_tokens = prefill_used + len(active)
         return len(active)
+
+    def _read_counters(self) -> None:
+        """Add the growth of the model's device counters since their last
+        read to ``stats`` (int32 on the device, so modulo 2**32). Called
+        once a decode's readback has waited for its step, which wrote
+        them: the read waits for nothing more."""
+        if not self._counters:
+            return
+        vals = jax.device_get([self.cache[k] for k in self._counters])
+        for k, v in zip(self._counters, vals):
+            v = int(v[0])
+            self.stats[k[5:]] += (v - self._counted[k]) % 2**32
+            self._counted[k] = v
 
     def _dispatching(self) -> None:
         """A prefill chunk or a decode is about to be queued on the chip:
@@ -2260,6 +2285,8 @@ class ServeEngine:
                 like["cross_len"] = self.cross_len
         state = deserialize_tree(blob[4 + mlen :], like)
         self.cache = jax.tree.map(jnp.asarray, state["cache"])
+        self._counted = {k: int(np.asarray(self.cache[k])[0])
+                         for k in self._counters}
         self.lengths = np.asarray(state["lengths"]).copy()
         self.last_token = np.asarray(state["last_token"]).copy()
         self.steps = int(state["steps"])

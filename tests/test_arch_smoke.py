@@ -61,6 +61,7 @@ def test_full_config_matches_assignment(arch):
         "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102_400),
         "zamba2-1.2b": (38, 2048, 32, 32, 8192, 32_000),
         "whisper-medium": (24, 1024, 16, 16, 4096, 51_865),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11_264, 163_840),
     }
     L, d, h, kv, ff, v = figures[arch]
     assert cfg.n_layers == L and cfg.d_model == d and cfg.vocab_size == v
