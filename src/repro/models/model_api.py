@@ -122,12 +122,19 @@ class ModelFns:
     #   state (SSM conv/ssm leaves) in addition to (or instead of) page
     #   pools. Such state is not page-addressable, so the engine's
     #   copy-on-write prefix sharing falls back to trie bookkeeping only.
+    # - pools_carried — True when prefill_chunk and decode_paged carry
+    #   every cache leaf whole through the layer scan, updating it in
+    #   place. The engine then donates the cache to those steps, so the
+    #   output aliases the input and no pool is copied. A scan that takes
+    #   the pools as ``xs`` and returns them as ``ys`` leaves this False:
+    #   donating it adds pool-sized copies into the aliased outputs.
     paged_cache_specs: Callable[..., Pytree] | None = None
     prefill_chunk: Callable[..., tuple[jax.Array, Pytree]] | None = None
     decode_paged: Callable[
         [Pytree, Pytree, dict], tuple[jax.Array, Pytree]
     ] | None = None
     paged_state: bool = False
+    pools_carried: bool = False
 
     # speculative verification (optional): one causal multi-query pass over
     # a W-token draft window. batch carries tokens (B, W), positions (B,)

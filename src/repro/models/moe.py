@@ -578,4 +578,6 @@ def make_model(cfg: ModelConfig) -> ModelFns:
                       else functools.partial(verify_paged_fn, cfg=cfg)),
         # pure page-pool cache: eligible for copy-on-write prefix sharing
         paged_state=False,
+        # _scan_cached carries every pool whole, for MLA and GQA alike
+        pools_carried=True,
     )

@@ -637,12 +637,21 @@ class ServeEngine:
                 self._prefill_chunk = jax.jit(_t_prefill,
                                               static_argnames=("offset",))
             else:
-                self._decode_paged = jax.jit(model.decode_paged)
+                # donate the cache where the family carries its pools
+                # whole through the scan: the pools are then updated in
+                # place, where an undonated carry first copies each pool.
+                # An xs/ys scan would gain copies from donation instead.
+                # The speculative branch above stays undonated: the
+                # draft's scan runs on the same cache.
+                donate = (1,) if model.pools_carried else ()
+                self._decode_paged = jax.jit(model.decode_paged,
+                                             donate_argnums=donate)
                 self._prefill_chunk = jax.jit(
                     model.prefill_chunk,
                     static_argnames=(
                         ("offset", "mm_len") if self._mm else ("offset",)
                     ),
+                    donate_argnums=donate,
                 )
             # donate the cache: COW duplicates one page in place instead
             # of materializing a second copy of every page pool

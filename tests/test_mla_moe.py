@@ -2,10 +2,12 @@
 block, ``repro.models.mla`` and ``repro.models.moe``) at the reduced
 preset on the CPU: the grouped matmul against its XLA twin, the sigmoid
 router, the absorbed decode against the expanded form, expert shares that
-add up to the whole layer, dropless dispatch, and the config's published
-size."""
+add up to the whole layer, dropless dispatch, the config's published
+size, and the engine's paged steps taking the family's cache donated."""
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -405,3 +407,220 @@ def test_expert_counters_count_what_the_grouped_matmul_read(first, held,
     clone = _engine(cfg, params, prefill_chunk=32)
     clone.restore(eng.snapshot())
     counted_run(clone, [list(range(3, 40))])
+
+
+# ---------------------------------------------------------------------------
+# Donating the paged cache to the engine's jitted steps
+# ---------------------------------------------------------------------------
+
+
+def _pool_copies(hlo: str, pools) -> int:
+    """``copy`` instructions in compiled HLO text whose result has the
+    shape and dtype of one of ``pools``."""
+    short = {"bfloat16": "bf16", "float32": "f32"}
+    shapes = {(short[str(v.dtype)], ",".join(map(str, v.shape)))
+              for v in pools}
+    found = re.findall(r"=\s*(\w+)\[([\d,]*)\]\S*\s+copy\(", hlo)
+    return sum(s in shapes for s in found)
+
+
+@pytest.mark.parametrize("arch", ["moonlight-16b-a3b", "granite-moe-1b-a400m",
+                                  "phi4-mini-3.8b"])
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_cache_is_donated_where_the_family_carries_its_pools(arch, step):
+    """The engine donates the cache to its paged steps only where the
+    family's scan carries the pools whole (``ModelFns.pools_carried``):
+    the output then aliases every pool, and the program has the fewest
+    pool-shaped copies of the two. A dense family's scan takes the pools
+    as ``xs`` and returns them as ``ys``: donated, it gains pool-sized
+    copies into the aliased outputs, so it is left undonated."""
+    cfg = REDUCED[arch]
+    model = get_model(cfg)
+    params = model.init(jax.random.key(0))
+    eng = _engine(cfg, params, prefill_chunk=32)
+    # one buffer cannot be donated twice: no two cache leaves share one
+    assert len({v.unsafe_buffer_pointer() for v in eng.cache.values()}) \
+        == len(eng.cache)
+    pools = [v for k, v in eng.cache.items() if k.endswith("_pages")]
+    width = eng.page_table.shape[1]
+    if step == "decode":
+        fn, engine_fn, kw = model.decode_paged, eng._decode_paged, {}
+        batch = {"tokens": jnp.zeros((4, 1), jnp.int32),
+                 "positions": jnp.zeros((4,), jnp.int32),
+                 "page_table": jnp.zeros((4, width), jnp.int32)}
+    else:
+        fn, engine_fn, kw = model.prefill_chunk, eng._prefill_chunk, {
+            "offset": 0}
+        batch = {"tokens": jnp.zeros((1, 32), jnp.int32),
+                 "valid": jnp.asarray(32, jnp.int32),
+                 "slot": jnp.asarray(0, jnp.int32),
+                 "page_table": jnp.zeros((width,), jnp.int32)}
+
+    def compiled(f):
+        return f.lower(params, eng.cache, batch, **kw).compile()
+
+    copies = {d: _pool_copies(compiled(jax.jit(
+        fn, donate_argnums=d, static_argnames=tuple(kw))).as_text(), pools)
+        for d in ((), (1,))}
+    got = compiled(engine_fn)
+    alias = got.memory_analysis().alias_size_in_bytes
+    assert _pool_copies(got.as_text(), pools) == min(copies.values())
+    if model.pools_carried:
+        assert alias >= sum(v.nbytes for v in pools)
+    else:
+        assert alias == 0
+        assert copies[(1,)] > copies[()]
+
+
+def _serve_snapshot_restore(new):
+    """Snapshot mid-decode; the engine goes on, and a fresh engine
+    restored from the blob serves the same requests to their end."""
+    eng = new()
+    reqs = [eng.submit(list(range(3 + i, 30 + 9 * i)), max_new_tokens=10)
+            for i in range(3)]
+    for _ in range(6):
+        eng.step()
+    assert any(r.generated for r in reqs) and not all(r.done for r in reqs)
+    clone = new()
+    clone.restore(eng.snapshot())
+    eng.run()
+    clone.run()
+    return ([r.generated for r in reqs]
+            + [clone.requests[r.req_id].generated for r in reqs]), {}
+
+
+def _serve_cow_prefix(new):
+    """A repeated page-aligned prompt hits every cached page: the last
+    one is copied on write (``_copy_pages``) for the recomputed last
+    token, beside a request that shares the first page only."""
+    eng = new()
+    prompt = list(range(5, 37))
+    first = eng.submit(prompt, max_new_tokens=4)
+    eng.run()
+    again = [eng.submit(prompt, max_new_tokens=6),
+             eng.submit(prompt[:16] + [7, 8], max_new_tokens=6)]
+    eng.run()
+    assert eng.stats["cow_copies"] > 0
+    return [first.generated] + [r.generated for r in again], {}
+
+
+def _spill_remote():
+    from repro.core.cloudlet import CloudletRegistry
+    from repro.core.reliability import ReliabilityRegistry
+    from repro.serving.kvcache import RemotePagePool
+
+    reg = CloudletRegistry()
+    reg.create("serve", "moonlight")
+    reg.join("serve", "h0")
+    rel = ReliabilityRegistry()
+    for h in ("h1", "h2"):
+        reg.join("serve", h)
+        rel.add_host(h)
+    return RemotePagePool(reg, "serve", "h0", reliability=rel)
+
+
+def _serve_spill_recall(new):
+    """Under page pressure cold prefix pages are lent to peers, and a
+    later hit recalls them (``_install_page``); decode pages stage on a
+    peer as they fill (write-behind ``extract_page_payload``)."""
+    eng = new(n_slots=1, max_seq=96, n_pages=6, remote_pool=_spill_remote(),
+              write_behind=True)
+    rng = np.random.default_rng(4)
+    prefixes = [rng.integers(1, CFG.vocab_size, 32).tolist()
+                for _ in range(2)]
+    out = []
+    for _ in range(2):
+        for pref in prefixes:
+            reqs = [eng.submit(pref + rng.integers(1, CFG.vocab_size,
+                                                   6).tolist(),
+                               max_new_tokens=12) for _ in range(2)]
+            eng.run(400)
+            out += [r.generated for r in reqs]
+    assert eng.stats["pages_spilled"] > 0 and eng.stats["pages_recalled"] > 0
+    assert eng.stats["pages_staged"] > 0
+    return out, {}
+
+
+def _serve_preempt_resume(new, remote):
+    """A low-priority request is preempted mid-decode by a high-priority
+    one and resumes: by recalling its lent chain with a spill tier, by
+    re-prefilling its tokens without one."""
+    from repro.serving.scheduler import SchedulerConfig
+
+    kw = {"remote_pool": _spill_remote(), "write_behind": True} \
+        if remote else {}
+    eng = new(n_slots=1, max_seq=96, n_pages=12,
+              scheduler=SchedulerConfig(token_budget=64, preempt_margin=2),
+              **kw)
+    prefix = list(range(11, 43))
+    low = eng.submit(prefix + [5, 6, 7], max_new_tokens=20, priority=0)
+    for _ in range(18):
+        eng.step()
+    assert low.slot is not None and len(low.generated) >= 2
+    high = eng.submit(prefix + [9, 9], max_new_tokens=4, priority=3)
+    eng.run(400)
+    assert low.done and high.done and eng.stats["preemptions"] == 1
+    if remote:
+        assert eng.stats["pages_staged"] > 0
+        assert eng.stats["recall_resumes"] == 1
+    else:
+        assert eng.stats["resume_mismatches"] == 0
+    return [low.generated, high.generated], {}
+
+
+def _serve_counters(new):
+    """The expert counters read back after each decode count the same
+    calls, hits and rows."""
+    eng = new()
+    reqs = [eng.submit(list(range(2, 20 + 13 * i)), max_new_tokens=8)
+            for i in range(4)]
+    for _ in range(12):
+        eng.step()
+    counts = {k: v for k, v in eng.stats.items() if k.startswith("moe_")}
+    assert counts["moe_decode_calls"] >= 5
+    eng.run()
+    return [r.generated for r in reqs], counts
+
+
+@pytest.mark.parametrize("scenario", [
+    _serve_snapshot_restore,
+    _serve_cow_prefix,
+    _serve_spill_recall,
+    functools.partial(_serve_preempt_resume, remote=True),
+    functools.partial(_serve_preempt_resume, remote=False),
+    _serve_counters,
+], ids=["snapshot-restore", "cow-prefix", "spill-recall",
+        "preempt-recall", "preempt-reprefill", "counters"])
+def test_donated_steps_serve_what_undonated_steps_serve(scenario):
+    """Every engine path that holds the paged cache (snapshot and
+    restore, copy on write, spill, recall and write-behind, preemption,
+    the counters' readback) serves the same tokens and counts when the
+    paged steps donate the cache as when they copy it. Each donated
+    decode's input cache is deleted, and no path reads it after."""
+    cfg = CFG
+    params = _params(cfg, seed=3)
+    inputs = {True: [], False: []}
+
+    def factory(donated):
+        def new(**kw):
+            kw = {"n_slots": 4, "max_seq": 128, "prefill_chunk": 32, **kw}
+            eng = ServeEngine(get_model(cfg), params, page_size=16, **kw)
+            if not donated:
+                eng._decode_paged = jax.jit(eng.model.decode_paged)
+                eng._prefill_chunk = jax.jit(eng.model.prefill_chunk,
+                                             static_argnames=("offset",))
+            decode = eng._decode_paged
+
+            def seen(params, cache, batch):
+                inputs[donated].append(cache["latent_pages"])
+                return decode(params, cache, batch)
+
+            eng._decode_paged = seen
+            return eng
+        return new
+
+    got = scenario(factory(True))
+    want = scenario(factory(False))
+    assert got == want
+    assert inputs[True] and all(a.is_deleted() for a in inputs[True])
+    assert not any(a.is_deleted() for a in inputs[False])
